@@ -154,4 +154,6 @@ def main(argv: Optional[List[str]] = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     main()

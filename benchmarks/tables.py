@@ -886,16 +886,20 @@ def table_fl_llm() -> List[Row]:
 def _roofline_fl_decode_agg() -> dict:
     model = (1 << 20) if FULL else (1 << 15)
     from repro.roofline.analysis import decode_agg_roofline
+    from repro.roofline.peaks import V5E
     return decode_agg_roofline(cohort=64, n_chunks=model // 256, latent=8,
-                               hidden=(32,), chunk=256, n_buckets=1)
+                               hidden=(32,), chunk=256, n_buckets=1,
+                               device_kind=V5E)
 
 
 def _roofline_fl_partition() -> dict:
     model = (1 << 20) if FULL else (1 << 15)
     bulk = model - model // 16
     from repro.roofline.analysis import decode_agg_roofline
+    from repro.roofline.peaks import V5E
     return decode_agg_roofline(cohort=64, n_chunks=bulk // 256, latent=8,
-                               hidden=(32,), chunk=256, n_buckets=2)
+                               hidden=(32,), chunk=256, n_buckets=2,
+                               device_kind=V5E)
 
 
 # =====================================================================

@@ -979,7 +979,6 @@ def _fused_chunked_decode_agg(spec: ChunkedAESpec, params: Params,
 def _sharded_callable(spec: CodecSpec, mesh: jax.sharding.Mesh):
     """Build (once per (spec, mesh)) the jitted shard_map reduction so
     repeated rounds dispatch a cached executable instead of re-tracing."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def shard_fn(params, stacked_shard, w_shard):
@@ -988,11 +987,11 @@ def _sharded_callable(spec: CodecSpec, mesh: jax.sharding.Mesh):
                              rows.astype(jnp.float32))
         return jax.lax.psum(partial, "clients")
 
-    # check_rep=False: pallas_call (the quantize/fused-dense kernels inside
-    # decode_batched) has no shard_map replication rule yet
-    return jax.jit(shard_map(shard_fn, mesh=mesh,
-                             in_specs=(P(), P("clients"), P("clients")),
-                             out_specs=P(), check_rep=False))
+    # check_vma=False: pallas_call (the quantize/fused-dense kernels inside
+    # decode_batched) has no varying-manual-axes rule
+    return jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                                 in_specs=(P(), P("clients"), P("clients")),
+                                 out_specs=P(), check_vma=False))
 
 
 def decode_and_aggregate_sharded(spec: CodecSpec, params: Optional[Params],

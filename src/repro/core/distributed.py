@@ -36,27 +36,6 @@ Pytree = Any
 DEFAULT_AE = ChunkedAEConfig(chunk_size=4096, hidden=(512,), latent_chunk=8)
 
 
-def _shard_map_compat(f, *, axis_names, in_specs, out_specs, mesh,
-                      nested=False):
-    """Partial-manual shard_map across jax versions: newer jax exposes
-    ``jax.shard_map(axis_names=..., check_vma=...)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with the complementary
-    ``auto=`` set and ``check_rep=``. For a ``nested`` region (inside an
-    already-Manual outer shard_map) new jax must infer the mesh from
-    context — passing the concrete mesh there re-introduces the outer axis;
-    the old API always takes it explicitly."""
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(axis_names=axis_names, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        if not nested:
-            kwargs["mesh"] = mesh
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, auto=auto)
-
-
 def leaf_encode(ae_params: Pytree, ae_cfg: ChunkedAEConfig,
                 leaf: jax.Array) -> jax.Array:
     """Flatten a param leaf into chunks and encode: (n_chunks, latent)."""
@@ -164,13 +143,13 @@ def build_fl_round_step(cfg: ArchConfig, shape: ShapeConfig, mesh: Mesh,
             grads = jax.lax.with_sharding_constraint(grads, grad_specs)
             ae_rep = jax.tree_util.tree_map(lambda _: PartitionSpec(),
                                             ae_params)
-            # nested manual region: mesh inferred from context on new jax
+            # nested manual region: the mesh is inferred from context
             # (the outer pod-manual shard_map has already marked `pod`
-            # Manual); the old-jax fallback takes it explicitly
-            decoded = _shard_map_compat(
+            # Manual; passing the concrete mesh would re-introduce it)
+            decoded = jax.shard_map(
                 _codec_local, axis_names={"data", "model"},
                 in_specs=(grad_specs, ae_rep), out_specs=grad_specs,
-                mesh=mesh, nested=True)(grads, ae_params)
+                check_vma=False)(grads, ae_params)
         else:
             # naive baseline: flatten+chunk whole leaves (GSPMD reshards)
             latents = encode_tree(ae_params, ae_cfg, grads)
@@ -213,10 +192,11 @@ def build_fl_round_step(cfg: ArchConfig, shape: ShapeConfig, mesh: Mesh,
     rep_o = jax.tree_util.tree_map(lambda _: P(), o_shapes)
     rep_ae = jax.tree_util.tree_map(lambda _: P(), ae_shapes)
 
-    sm = _shard_map_compat(
+    sm = jax.shard_map(
         per_pod, mesh=mesh, axis_names={"pod"},
         in_specs=(rep, rep_o, rep_ae, sm_batch_in),
-        out_specs=(rep, rep_o, {"loss": P(), "accuracy": P()}))
+        out_specs=(rep, rep_o, {"loss": P(), "accuracy": P()}),
+        check_vma=False)
 
     def step(params, opt_state, ae_params, batch):
         # token-embedding gather OUTSIDE the manual region: the SPMD

@@ -128,7 +128,7 @@ def init_state(cfg: ServeConfig, codec_params: Optional[Pytree] = None,
     cis = jnp.arange(n, dtype=jnp.int32)
     if global_flat is None:
         global_flat = jnp.zeros((int(cfg.spec.size),), jnp.float32)
-    return {
+    state = {
         "times": _latency(cfg, key, cis),            # (N,) next arrival
         "seqs": cis,                                 # (N,) dispatch seq
         "versions": jnp.zeros(n, jnp.int32),         # (N,) model at dispatch
@@ -137,6 +137,17 @@ def init_state(cfg: ServeConfig, codec_params: Optional[Pytree] = None,
         "version": jnp.int32(0),
         "next_seq": jnp.int32(n),
     }
+    if cfg.shard:
+        # replicated on the clients mesh, as the sharded step returns it:
+        # round 2 then reuses round 1's executable instead of recompiling
+        state = jax.device_put(state, jax.sharding.NamedSharding(
+            _clients_mesh(), jax.sharding.PartitionSpec()))
+    return state
+
+
+def _clients_mesh() -> jax.sharding.Mesh:
+    """The 1-D ``clients`` mesh over every device (``shard=True``)."""
+    return jax.sharding.Mesh(np.array(jax.devices()), ("clients",))
 
 
 def _decode_aggregate(cfg: ServeConfig, params: Optional[Pytree],
@@ -146,9 +157,8 @@ def _decode_aggregate(cfg: ServeConfig, params: Optional[Pytree],
     # cohort axis over a 1-D device mesh, inlined into the donated step:
     # each device reduces its shard's weighted sum (weights are globally
     # normalized), one psum makes the mean — codec.py §7.2 layout
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.sharding.Mesh(np.array(jax.devices()), ("clients",))
+    mesh = _clients_mesh()
     assert cfg.buffer_k % mesh.devices.size == 0, (
         f"buffer_k={cfg.buffer_k} must divide over {mesh.devices.size} "
         "devices")
@@ -159,9 +169,11 @@ def _decode_aggregate(cfg: ServeConfig, params: Optional[Pytree],
             jnp.einsum("c,cp->p", w_shard.astype(jnp.float32),
                        rows.astype(jnp.float32)), "clients")
 
-    return shard_map(shard_fn, mesh=mesh,
-                     in_specs=(P(), P("clients"), P("clients")),
-                     out_specs=P(), check_rep=False)(params, stacked, w)
+    # check_vma=False: pallas_call (the kernels inside decode_batched) has
+    # no varying-manual-axes rule
+    return jax.shard_map(shard_fn, mesh=mesh,
+                         in_specs=(P(), P("clients"), P("clients")),
+                         out_specs=P(), check_vma=False)(params, stacked, w)
 
 
 def make_step(cfg: ServeConfig, codec_params: Optional[Pytree] = None):

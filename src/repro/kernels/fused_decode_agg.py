@@ -14,55 +14,107 @@ expansion:
 
     out = Σ_blocks ( Σ_{c∈block} w_c · h_c ) @ W_dec  + b_dec   (Σ_c w_c = 1)
 
-Grid: ``(M/bm, C/bc)`` with the client-block axis innermost. Each output
-tile ``(bm, N)`` stays resident in VMEM while the kernel walks the cohort
-blocks: per step, a VPU reduction collapses ``bc`` clients' hidden tiles
-into one weighted tile (latent-sided — ``bc·bm·K`` floats), a single MXU
-matmul expands it to chunk width, and the result accumulates into the
-output; the bias is added on the first block. Full-model-sized data exists
-exactly once (the accumulator) — peak memory ``O(model)``, not
-``O(cohort × model)``; per-client tensors never reach chunk width even in
-VMEM (memory math in DESIGN.md §7.1).
+Grid: ``(N/bn, row tiles, C/bc)`` with the client-block axis innermost.
+Each output tile ``(bm, bn)`` stays resident in VMEM while the kernel walks
+the cohort blocks: per step, a VPU reduction collapses ``bc`` clients'
+hidden tiles into one weighted tile (latent-sided — ``bc·bm·K`` floats), a
+single MXU matmul expands it to ``bn`` columns, and the result accumulates
+into the output; the bias is added on the first block. The column axis is
+outermost, so each ``(K, bn)`` decoder band is fetched once per launch.
+Full-model-sized data exists exactly once (the accumulator) — peak memory
+``O(model)``, not ``O(cohort × model)`` (memory math in DESIGN.md §7.1).
 
-VMEM per step: ``bc·bm·K + K·N + bm·N`` floats; at the defaults
-(bc=16, bm=128, K≤512, N≤4096) ≈ 14.5 MB f32, inside the ~16 MB/core v5e
-budget (K=512 is the production hidden width; the codec defaults use
-K=32-64 where this is ≪1 MB).
-Validated against the pure-jnp oracle ``ref.fused_decode_agg_ref`` (which
-materializes the per-client decoded tensors this kernel avoids) in
-interpret mode (DESIGN.md §7.3, tests/test_kernels.py).
+VMEM: the pipeline double-buffers every block, and the reduction and the
+matmul each hold a temporary, so one step needs about
+``3·bc·bm·K + 2·K·bn + 3·bm·bn`` floats. :func:`_tile_plan` shrinks
+``bc``, then ``bn``, then ``bm`` until that fits :data:`VMEM_BUDGET`, and
+the launch raises the scoped-VMEM limit to :data:`VMEM_LIMIT`. At the
+production chunked AE (K=512, N=4096) the untiled step would need ~60 MB
+at bc=16 and the v5e compiler refuses it; the plan runs bc=8, bn=2048
+(≈18 MB). Compiled for v5e in tests/test_tpu_compile.py; validated
+against the pure-jnp oracle ``ref.fused_decode_agg_ref`` in interpret mode
+(DESIGN.md §7.3, tests/test_kernels.py).
 
 Under per-layer codec partitions (DESIGN.md §10.2) the grouped server path
 launches this kernel once per kernel-path chunked-AE (partition, spec)
 bucket per round — ``M`` is then the *group's* chunk count, not the whole
-model's, so the VMEM budget above holds per launch and shrinks with the
-partition; the weighted client reduction still commutes because each
+model's; the weighted client reduction still commutes because each
 bucket's weights are renormalized to Σ=1 before dispatch.
 """
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# scoped VMEM the launch may use, and the per-step estimate the tile plan
+# keeps under it (the gap is the compiler's own scratch)
+VMEM_LIMIT = 32 * 2 ** 20
+VMEM_BUDGET = 20 * 2 ** 20
 
-def _fused_decode_agg_kernel(w_ref, h_ref, wl_ref, b_ref, o_ref):
-    cb = pl.program_id(1)
-    w = w_ref[...].astype(jnp.float32)       # (bc, 1) client-block weights
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _step_vmem_bytes(bc: int, bm: int, K: int, bn: int) -> int:
+    """f32 bytes one grid step holds: double-buffered weight, hidden,
+    decoder-band, bias and output blocks, plus the weighted-product and
+    matmul temporaries."""
+    return 4 * (2 * (bc + bc * bm * K + K * bn + bn + bm * bn)
+                + bc * bm * K + bm * K + bm * bn)
+
+
+def _tile_plan(C: int, M: int, K: int, N: int, bm: int,
+               bc: int) -> Tuple[int, int, int]:
+    """``(bm, bc, bn)`` for a launch over ``C`` clients and ``M`` rows.
+
+    Row tiles are balanced (``ceil(M / tiles)`` rounded up to the 8-row
+    sublane) so a 135-row bucket pads to 144, not 256. Then, while the step
+    exceeds :data:`VMEM_BUDGET`: halve ``bc`` (free — it only adds grid
+    steps), else halve ``bn`` (each column band re-reads the hidden
+    activations), else halve ``bm``. Every halving keeps the block shapes
+    legal for the TPU tiling: ``bc`` a multiple of 8 or the whole padded
+    client axis, ``bn`` a multiple of 128 or the whole ``N``, ``bm`` a
+    multiple of 8."""
+    tiles = _cdiv(M, bm)
+    bm = _cdiv(_cdiv(M, tiles), 8) * 8
+    bc = min(bc, C)
+    bn = N
+    while _step_vmem_bytes(bc, bm, K, bn) > VMEM_BUDGET:
+        if bc % 16 == 0:
+            bc //= 2
+        elif bn % 256 == 0:
+            bn //= 2
+        elif bm % 16 == 0:
+            bm //= 2
+        else:
+            break
+    return bm, bc, bn
+
+
+def _decode_agg_kernel(desc_ref, w_ref, h_ref, wl_ref, b_ref, o_ref):
+    """Per grid step ``(j, t, cb)``: column band ``j``, row tile ``t`` (one
+    (bucket, m-tile) pair resolved through the prefetched descriptor
+    table), client block ``cb`` (zero-weight padded up to the cohort-wide
+    maximum). Reduce the client block, then expand."""
+    del desc_ref                             # consumed by the index maps
+    cb = pl.program_id(2)
+    w = w_ref[0].astype(jnp.float32)         # (bc, 1) this bucket's weights
     h = h_ref[...].astype(jnp.float32)       # (bc, bm, K)
     # weighted client reduction BEFORE the chunk-wide expansion (VPU,
     # latent-sided): Σ_{c∈block} w_c · h_c → (bm, K)
     hbar = jnp.sum(h * w[:, :, None], axis=0)
-    y = jnp.dot(hbar, wl_ref[...].astype(jnp.float32),
+    y = jnp.dot(hbar, wl_ref[0].astype(jnp.float32),
                 preferred_element_type=jnp.float32)
 
     @pl.when(cb == 0)
     def _init():
-        o_ref[...] = (y + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+        o_ref[...] = (y + b_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
 
     @pl.when(cb > 0)
     def _accum():
@@ -81,65 +133,21 @@ def fused_decode_agg(h: jax.Array, weights: jax.Array, w_last: jax.Array,
     is added once, which equals the weighted mean of per-client biases only
     under that normalization);
     w_last: (K, N), b_last: (N,) final decoder layer → (M, N).
-    ``bc`` is the client-block size per grid step (zero-weight padded).
+    ``bm``/``bc`` cap the row tile and the client block (zero-weight
+    padded); :func:`_tile_plan` may shrink both to fit VMEM. This is the
+    one-bucket case of :func:`grouped_fused_decode_agg` — the same launch.
     """
     C, M, K = h.shape
     K2, N = w_last.shape
     assert K == K2 and b_last.shape == (N,) and weights.shape == (C,)
-    bm = min(bm, max(8, M))
-    bc = min(bc, C)
-    Mp = -(-M // bm) * bm
-    Cp = -(-C // bc) * bc
-    if (Mp, Cp) != (M, C):
-        h = jnp.pad(h, ((0, Cp - C), (0, Mp - M), (0, 0)))
-    w2 = weights.astype(jnp.float32)
-    if Cp != C:
-        w2 = jnp.pad(w2, (0, Cp - C))      # zero weight ⇒ zero contribution
-    w2 = w2.reshape(Cp, 1)
-    bp = b_last.reshape(1, N)
-
-    out = pl.pallas_call(
-        _fused_decode_agg_kernel,
-        grid=(Mp // bm, Cp // bc),
-        in_specs=[
-            pl.BlockSpec((bc, 1), lambda i, c: (c, 0)),
-            pl.BlockSpec((bc, bm, K), lambda i, c: (c, i, 0)),
-            pl.BlockSpec((K, N), lambda i, c: (0, 0)),
-            pl.BlockSpec((1, N), lambda i, c: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, N), lambda i, c: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.float32),
-        interpret=interpret,
-    )(w2, h, w_last, bp)
-    return out[:M]
+    return grouped_fused_decode_agg([h], [weights], w_last[None],
+                                    b_last[None], [0], bm=bm, bc=bc,
+                                    interpret=interpret)[0]
 
 
 # =====================================================================
 # grouped ragged launch: one kernel sweep over every bucket of a round
 # =====================================================================
-def _grouped_decode_agg_kernel(desc_ref, w_ref, h_ref, wl_ref, b_ref,
-                               o_ref):
-    """Per grid step ``(t, cb)``: tile ``t`` is one (bucket, m-tile) pair
-    resolved through the prefetched descriptor table; ``cb`` walks the
-    bucket's client blocks (zero-weight padded up to the cohort-wide
-    maximum). Same reduce-before-expand body as the per-bucket kernel."""
-    del desc_ref                             # consumed by the index maps
-    cb = pl.program_id(1)
-    w = w_ref[...].astype(jnp.float32)       # (1, bc) this bucket's weights
-    h = h_ref[...].astype(jnp.float32)       # (bc, bm, K)
-    hbar = jnp.sum(h * w[0, :, None, None], axis=0)
-    y = jnp.dot(hbar, wl_ref[0].astype(jnp.float32),
-                preferred_element_type=jnp.float32)
-
-    @pl.when(cb == 0)
-    def _init():
-        o_ref[...] = (y + b_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
-
-    @pl.when(cb > 0)
-    def _accum():
-        o_ref[...] = (o_ref[...].astype(jnp.float32) + y).astype(o_ref.dtype)
-
-
 def grouped_fused_decode_agg(hs: Sequence[jax.Array],
                              weights: Sequence[jax.Array],
                              w_stack: jax.Array, b_stack: jax.Array,
@@ -166,7 +174,9 @@ def grouped_fused_decode_agg(hs: Sequence[jax.Array],
     column band and the output tile), row 2 the decoder index. The table
     rides the scalar-prefetch operand of a ``PrefetchScalarGridSpec``, so
     the index maps resolve every block address from SMEM before the DMA
-    fires — raggedness costs descriptor lookups, not extra launches.
+    fires — raggedness costs descriptor lookups, not extra launches. The
+    packed weights are ``(B, Cp, 1)`` so each ``(1, bc, 1)`` block keeps
+    the client axis on sublanes, as the TPU tiling requires.
 
     Packing: client axis padded to the cohort-wide max block count (zero
     weight ⇒ exact zero contribution), each bucket's rows padded to a
@@ -191,8 +201,8 @@ def grouped_fused_decode_agg(hs: Sequence[jax.Array],
             f"require one (K, N) signature; split the launch")
         assert weights[b].shape == (C_b,) and M_b > 0
         assert 0 <= dec_idx[b] < D
-    bm = min(bm, max(8, max(hs[b].shape[1] for b in live)))
-    bc = min(bc, max(hs[b].shape[0] for b in live))
+    bm, bc, bn = _tile_plan(max(hs[b].shape[0] for b in live),
+                            max(hs[b].shape[1] for b in live), K, N, bm, bc)
     Cp = max(-(-hs[b].shape[0] // bc) * bc for b in live)
 
     # pack: clients → shared padded axis, rows → bm-padded bands, and the
@@ -213,26 +223,30 @@ def grouped_fused_decode_agg(hs: Sequence[jax.Array],
             row_of.append(pos // bm + i)
             dec_of.append(dec_idx[b])
         pos += Mp_b
-    h_packed = jnp.concatenate(h_bands, axis=1)        # (Cp, Mtot, K)
-    w_packed = jnp.stack(w_rows)                       # (B_live, Cp)
+    h_packed = (h_bands[0] if len(h_bands) == 1
+                else jnp.concatenate(h_bands, axis=1))   # (Cp, Mtot, K)
+    w_packed = jnp.stack(w_rows)[:, :, None]             # (B_live, Cp, 1)
     desc = jnp.asarray([bucket_of, row_of, dec_of], jnp.int32)
     T = len(bucket_of)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(T, Cp // bc),
+        grid=(N // bn, T, Cp // bc),
         in_specs=[
-            pl.BlockSpec((1, bc), lambda t, cb, d: (d[0, t], cb)),
-            pl.BlockSpec((bc, bm, K), lambda t, cb, d: (cb, d[1, t], 0)),
-            pl.BlockSpec((1, K, N), lambda t, cb, d: (d[2, t], 0, 0)),
-            pl.BlockSpec((1, 1, N), lambda t, cb, d: (d[2, t], 0, 0)),
+            pl.BlockSpec((1, bc, 1), lambda j, t, cb, d: (d[0, t], cb, 0)),
+            pl.BlockSpec((bc, bm, K), lambda j, t, cb, d: (cb, d[1, t], 0)),
+            pl.BlockSpec((1, K, bn), lambda j, t, cb, d: (d[2, t], 0, j)),
+            pl.BlockSpec((1, 1, bn), lambda j, t, cb, d: (d[2, t], 0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, N), lambda t, cb, d: (d[1, t], 0)),
+        out_specs=pl.BlockSpec((bm, bn), lambda j, t, cb, d: (d[1, t], j)),
     )
     out = pl.pallas_call(
-        _grouped_decode_agg_kernel,
+        _decode_agg_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((pos, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(desc, w_packed, h_packed, w_stack, b_stack.reshape(D, 1, N))
 

@@ -10,9 +10,10 @@ HBM round-trip.
 Tiling: grid over (M/bm, N/bn) output tiles; each step streams an
 (bm, K) row-band of x and a (K, bn) column-band of w into VMEM and drives the
 MXU with a single ``jnp.dot``. bm/bn default to 128 — the MXU systolic array
-edge — and K (chunk size ≤ 4096) stays resident, so VMEM use is
-bm*K + K*bn + bm*bn floats ≈ 4.2 MB at f32 defaults, within the ~16 MB/core
-budget for v5e.
+edge — and K (chunk size ≤ 4096) stays resident. The pipeline double-buffers
+every block, so one step holds 2·(bm·K + K·bn + bn + bm·bn) f32 values:
+≈ 8.1 MiB at K=4096, inside v5e's 16 MiB default scoped-VMEM limit
+(compiled for v5e at the chunked AE's shapes in tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 

@@ -28,6 +28,7 @@ from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import build_step          # noqa: E402
 from repro.models import sharding as shard_lib     # noqa: E402
 from repro.roofline.analysis import analyze_compiled, model_flops  # noqa: E402
+from repro.roofline.peaks import V5E              # noqa: E402
 
 REPORT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                           "reports", "dryrun")
@@ -57,8 +58,9 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
+    # the production meshes model v5e pods (launch/mesh.py)
     report = analyze_compiled(tag, compiled, mesh.size,
-                              model_flops(cfg, shape),
+                              model_flops(cfg, shape), device_kind=V5E,
                               pod_boundary=256 if multi_pod else 0)
     row = report.row()
     row.update({

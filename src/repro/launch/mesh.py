@@ -10,23 +10,22 @@ state; the dry-run sets XLA_FLAGS for 512 host devices before first init.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the step functions place
+    arrays with ``with_sharding_constraint`` and partial-manual
+    ``shard_map``, which only accept Auto axes (``make_mesh`` defaults to
+    Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh() -> Mesh:
-    """Degenerate mesh over whatever devices exist (CPU smoke/examples)."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
-
-
-# TPU v5e hardware constants for the roofline model (per chip / per link)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link
+    return auto_mesh(shape, axes)

@@ -27,6 +27,7 @@ from repro.checkpoint.checkpoint import save_pytree
 from repro.configs import get_config
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.data.pipeline import synthetic_lm_batch
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import init_params, param_count
 from repro.models import sharding as shard_lib
 from repro.optim.optimizers import make_optimizer
@@ -57,6 +58,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.preset:
         cfg = LM100M if args.preset == "lm100m" else LM25M
@@ -73,8 +75,9 @@ def main() -> None:
     if args.mode == "fl":
         from repro.core.autoencoder import ChunkedAEConfig, init_chunked_ae
         from repro.core.distributed import build_fl_round_step
-        mesh = jax.make_mesh((1, 1, len(jax.devices())),
-                             ("pod", "data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((1, 1, len(jax.devices())),
+                         ("pod", "data", "model"))
         shape = ShapeConfig("cli", args.seq, args.batch, "train")
         ae_cfg = ChunkedAEConfig(chunk_size=512, hidden=(128,),
                                  latent_chunk=16)

@@ -1,18 +1,19 @@
 """Roofline analysis from compiled dry-run artifacts (no real hardware).
 
-Three terms per (arch × shape × mesh), in seconds:
+Three terms per (arch × shape × mesh), in seconds, against the peaks of
+the named chip (``roofline/peaks.py``, keyed by ``device_kind``):
 
-  compute    = HLO_FLOPs_per_device / PEAK_FLOPS
-  memory     = HLO_bytes_per_device / HBM_BW
-  collective = collective_bytes_per_device / ICI_BW
+  compute    = HLO_FLOPs_per_device / peak bf16 FLOP/s
+  memory     = HLO_bytes_per_device / HBM bandwidth
+  collective = collective_bytes_per_device / ICI bandwidth per link
 
 ``cost_analysis()`` is already per-device (the SPMD-partitioned module), so
 no extra division by chip count. Collective bytes are NOT in cost_analysis —
 we parse the post-partitioning HLO text and sum the operand sizes of every
 all-reduce / all-gather / reduce-scatter / all-to-all / collective-permute /
 ragged-all-to-all instruction (shapes in SPMD HLO are per-device shards).
-ICI assumption: one effective 50 GB/s link per chip (conservative; v5e has
-multiple links — we report the term, not a latency promise).
+ICI assumption: one effective link per chip (conservative; v5e has four
+— we report the term, not a latency promise).
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.roofline.peaks import ChipPeaks, peaks_for
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -98,6 +99,7 @@ class RooflineReport:
     collective_breakdown: Dict[str, float]
     peak_memory_per_device: float
     model_flops: float                  # 6*N*D (or mode-appropriate)
+    peaks: ChipPeaks
     attn_loop_bytes_per_device: float = 0.0
     cross_pod_bytes_per_device: float = 0.0
     compute_s: float = 0.0
@@ -106,14 +108,15 @@ class RooflineReport:
     collective_s: float = 0.0
 
     def __post_init__(self):
-        self.compute_s = self.flops_per_device / PEAK_FLOPS_BF16
-        self.memory_s = self.hbm_bytes_per_device / HBM_BW
+        pk = self.peaks
+        self.compute_s = self.flops_per_device / pk.flops_bf16
+        self.memory_s = self.hbm_bytes_per_device / pk.hbm_bw
         # fused-attention estimate: on TPU the flash score/prob tiles stay
         # in VMEM inside the Pallas kernel (kernels/flash_attention.py) —
         # remove their modeled HBM traffic from the memory term.
         self.memory_fused_s = (self.hbm_bytes_per_device
-                               - self.attn_loop_bytes_per_device) / HBM_BW
-        self.collective_s = self.collective_bytes_per_device / ICI_BW
+                               - self.attn_loop_bytes_per_device) / pk.hbm_bw
+        self.collective_s = self.collective_bytes_per_device / pk.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -149,9 +152,10 @@ class RooflineReport:
 
 
 def analyze_compiled(name: str, compiled, n_devices: int,
-                     model_flops: float,
+                     model_flops: float, *, device_kind: str,
                      pod_boundary: int = 0) -> RooflineReport:
-    """Roofline terms from the compiled SPMD executable.
+    """Roofline terms from the compiled SPMD executable, against the peaks
+    of ``device_kind`` (the chip the program is compiled for).
 
     Uses the trip-count-aware static HLO analyzer (hlo_parse) — XLA's own
     ``cost_analysis()`` visits while bodies once, undercounting scanned-layer
@@ -176,7 +180,8 @@ def analyze_compiled(name: str, compiled, n_devices: int,
         cross_pod_bytes_per_device=cost.cross_pod_bytes,
         collective_bytes_per_device=cost.collective_bytes,
         collective_breakdown=dict(cost.collective_breakdown),
-        peak_memory_per_device=peak, model_flops=model_flops)
+        peak_memory_per_device=peak, model_flops=model_flops,
+        peaks=peaks_for(device_kind))
 
 
 # =====================================================================
@@ -284,7 +289,7 @@ def model_flops(cfg, shape) -> float:
 # =====================================================================
 def decode_agg_roofline(cohort: int, n_chunks: int, latent: int,
                         hidden: Tuple[int, ...], chunk: int, *,
-                        n_buckets: int = 1,
+                        device_kind: str, n_buckets: int = 1,
                         dtype_bytes: int = 4) -> Dict[str, Dict]:
     """Place the chunked-AE decode→aggregate variants on the memory roofline.
 
@@ -308,8 +313,9 @@ def decode_agg_roofline(cohort: int, n_chunks: int, latent: int,
 
     Returns per-variant dicts with ``flops``, ``hbm_bytes``,
     ``arith_intensity`` (FLOPs/byte), ``pct_of_roof`` (attainable FLOP/s at
-    that intensity over peak), ``bound`` and ``launches``, plus the machine
-    constants used — all finite for any positive shapes
+    that intensity over the peak of ``device_kind``), ``bound`` and
+    ``launches``, plus the machine constants used — all finite for any
+    positive shapes
     (tests/test_roofline_decode_agg.py)."""
     assert cohort > 0 and n_chunks > 0 and latent > 0 and chunk > 0
     assert n_buckets > 0 and dtype_bytes > 0
@@ -325,16 +331,17 @@ def decode_agg_roofline(cohort: int, n_chunks: int, latent: int,
     model_bytes = n_buckets * n_chunks * chunk * dtype_bytes   # one mean
     recon_bytes = cohort * model_bytes          # C materialized decodes
     hidden_rt = n_buckets * cohort * n_chunks * K * dtype_bytes
-    ridge = PEAK_FLOPS_BF16 / HBM_BW
+    pk = peaks_for(device_kind)
+    ridge = pk.flops_bf16 / pk.hbm_bw
 
     def variant(hbm_bytes: float, launches: int) -> Dict[str, float]:
         ai = flops / hbm_bytes
-        attainable = min(PEAK_FLOPS_BF16, ai * HBM_BW)
+        attainable = min(pk.flops_bf16, ai * pk.hbm_bw)
         return {
             "flops": flops,
             "hbm_bytes": hbm_bytes,
             "arith_intensity": ai,
-            "pct_of_roof": 100.0 * attainable / PEAK_FLOPS_BF16,
+            "pct_of_roof": 100.0 * attainable / pk.flops_bf16,
             "bound": "memory" if ai < ridge else "compute",
             "launches": launches,
         }
@@ -343,8 +350,8 @@ def decode_agg_roofline(cohort: int, n_chunks: int, latent: int,
         "shape": {"cohort": cohort, "n_chunks": n_chunks, "latent": latent,
                   "hidden": list(hidden), "chunk": chunk,
                   "n_buckets": n_buckets},
-        "machine": {"hbm_bw": HBM_BW, "peak_flops": PEAK_FLOPS_BF16,
-                    "ridge_intensity": ridge},
+        "machine": {"device_kind": device_kind, "hbm_bw": pk.hbm_bw,
+                    "peak_flops": pk.flops_bf16, "ridge_intensity": ridge},
         "loop": variant(
             z_bytes + n_buckets * cohort * param_bytes    # params per client
             + 2.0 * recon_bytes                           # write + read back

@@ -13,6 +13,7 @@ import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import SHAPES, get_config
+from repro.launch.mesh import auto_mesh
 from repro.models.attention import flash_attention
 from repro.models.partition_ctx import activation_sharding, \
     constrain_activations
@@ -32,7 +33,7 @@ def test_constrain_activations_noop_without_context():
 
 def test_constrain_activations_applies_in_context():
     """Under a 1-device mesh the constraint must be a semantic no-op."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     x = jnp.arange(24, dtype=jnp.float32).reshape(2, 4, 3)
     with mesh:
         with activation_sharding(("data",), "model"):
@@ -43,12 +44,7 @@ def test_constrain_activations_applies_in_context():
 def test_fully_shard_adds_data_axis_to_big_leaves():
     from repro.launch.steps import param_shapes
     from repro.models import sharding as shard_lib
-    # jax changed the AbstractMesh ctor across 0.4.x: older builds take
-    # (shape, axis_names), 0.4.37+ takes a tuple of (name, size) pairs
-    try:
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
-    except TypeError:
-        mesh = AbstractMesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("llama3-8b")
     shapes = param_shapes(cfg)
     specs = shard_lib.param_specs(shapes, mesh)

@@ -7,6 +7,7 @@ import math
 import pytest
 
 from repro.roofline.analysis import decode_agg_roofline
+from repro.roofline.peaks import PEAKS, V5E, peaks_for
 
 VARIANTS = ("loop", "vmap", "fused", "grouped")
 
@@ -22,7 +23,7 @@ SHAPES = [
 
 @pytest.mark.parametrize("shape", SHAPES)
 def test_all_variants_finite_and_positive(shape):
-    roof = decode_agg_roofline(**shape)
+    roof = decode_agg_roofline(**shape, device_kind=V5E)
     for v in VARIANTS:
         r = roof[v]
         for field in ("flops", "hbm_bytes", "arith_intensity",
@@ -36,7 +37,8 @@ def test_all_variants_finite_and_positive(shape):
 
 def test_variant_ordering_matches_design():
     roof = decode_agg_roofline(cohort=64, n_chunks=128, latent=8,
-                               hidden=(32,), chunk=256, n_buckets=2)
+                               hidden=(32,), chunk=256, n_buckets=2,
+                               device_kind=V5E)
     # same decoder math everywhere
     assert len({roof[v]["flops"] for v in VARIANTS}) == 1
     # traffic strictly shrinks loop → vmap → fused → grouped (the fused
@@ -58,4 +60,19 @@ def test_variant_ordering_matches_design():
 def test_rejects_degenerate_shapes():
     with pytest.raises(AssertionError):
         decode_agg_roofline(cohort=0, n_chunks=1, latent=1, hidden=(),
-                            chunk=8)
+                            chunk=8, device_kind=V5E)
+
+
+def test_peaks_keyed_by_device_kind():
+    """The roof is the named chip's: v5e's published peaks, and an error
+    (never a default) for a kind the table does not hold."""
+    roof = decode_agg_roofline(cohort=8, n_chunks=4, latent=8, hidden=(32,),
+                               chunk=256, device_kind=V5E)
+    assert roof["machine"]["peak_flops"] == PEAKS[V5E].flops_bf16 == 197e12
+    assert roof["machine"]["hbm_bw"] == 819e9
+    for kind in ("cpu", "TPU v4", ""):
+        with pytest.raises(KeyError):
+            peaks_for(kind)
+        with pytest.raises(KeyError):
+            decode_agg_roofline(cohort=8, n_chunks=4, latent=8,
+                                hidden=(32,), chunk=256, device_kind=kind)

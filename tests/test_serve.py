@@ -123,6 +123,19 @@ def test_global_flat_seed_passthrough():
                                   np.asarray(g0))
 
 
+@pytest.mark.parametrize("shard", [False, True])
+def test_step_compiles_once(shard):
+    """The state ``init_state`` places has the shardings the step returns,
+    so every round after the first reuses one executable — the timed loop
+    of ``run_serve`` (after one warmup round) never compiles."""
+    cfg = _cfg(n_clients=32, buffer_k=8, shard=shard)
+    step = make_step(cfg)
+    state = init_state(cfg)
+    for _ in range(3):
+        state = step(state)
+    assert step._cache_size() == 1
+
+
 def test_shard_single_device_matches_unsharded():
     """shard=True agrees with the plain fused path up to reduction-order
     float drift (the sharded path sums weighted rows via einsum + psum)."""

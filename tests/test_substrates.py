@@ -106,17 +106,9 @@ def test_checkpoint_roundtrip(tmp_path):
 
 # ------------------------------------------------------------------ sharding
 def _abstract_mesh(multi=False):
-    # jax changed the AbstractMesh ctor across 0.4.x: older builds take
-    # (shape, axis_names), 0.4.37+ takes a tuple of (name, size) pairs
     if multi:
-        dims = (("pod", 2), ("data", 16), ("model", 16))
-    else:
-        dims = (("data", 16), ("model", 16))
-    try:
-        return AbstractMesh(tuple(dims))
-    except TypeError:
-        return AbstractMesh(tuple(s for _, s in dims),
-                            tuple(n for n, _ in dims))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

@@ -15,6 +15,7 @@ from repro.configs.paper import MNIST_CLASSIFIER, AEConfig
 from repro.core import (FCAECompressor, FLConfig, FederatedRun, run_prepass,
                         validation_model_curve, fc_reconstruct)
 from repro.data.pipeline import dirichlet_partition, mnist_like
+from repro.launch.mesh import auto_mesh
 
 
 @pytest.fixture(scope="module")
@@ -78,7 +79,7 @@ def test_distributed_fl_round_degenerate_mesh():
     from repro.optim.optimizers import make_optimizer
     import dataclasses
 
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = auto_mesh((1, 1, 1), ("pod", "data", "model"))
     cfg = get_config("llama3-8b").reduced()
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=16,
                                 global_batch=2)
