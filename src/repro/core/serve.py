@@ -17,7 +17,7 @@ are re-dispatched — all staged as **one donated jitted step**:
   (PRNG keyed on the dispatch sequence), so the step prices exactly the
   server's work — decode + staleness-weighted aggregate + re-dispatch —
   with zero host payload traffic;
-* ``jax.jit(step, donate_argnums=0)`` donates the whole state pytree:
+* ``jax.jit(serve_step, donate_argnums=0)`` donates the whole state pytree:
   XLA writes round r+1's state into round r's buffers, so the
   steady-state footprint is **two** generations of state (the classic
   double-buffer), not one per round. The invariant donation imposes: the
@@ -53,6 +53,15 @@ from repro.core import codec
 from repro.core.arrival import pop_k_device
 
 Pytree = Any
+
+# The step's four layers, as ``jax.named_scope`` names: every op of the
+# compiled step carries exactly one of them in its ``op_name`` metadata, so
+# a profiler trace's device ops group by layer (README "Streaming serve").
+SCOPE_POP = "serve.pop"                  # first-K pop, clock, weights
+SCOPE_PAYLOADS = "serve.payloads"        # synthetic cohort (simulation)
+SCOPE_AGGREGATE = "serve.aggregate"      # decode→aggregate, global update
+SCOPE_REDISPATCH = "serve.redispatch"    # latencies, queue scatters
+SCOPES = (SCOPE_POP, SCOPE_PAYLOADS, SCOPE_AGGREGATE, SCOPE_REDISPATCH)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,42 +190,58 @@ def make_step(cfg: ServeConfig, codec_params: Optional[Pytree] = None):
     round. Everything — pop, payload synthesis, fused decode→aggregate,
     model update, re-dispatch — is one XLA computation; the state pytree
     is donated (``donate_argnums=0``), so each round's output overwrites
-    the previous round's buffers (double-buffered steady state)."""
+    the previous round's buffers (double-buffered steady state). Each of
+    those layers runs under its own name of :data:`SCOPES`; the names are
+    metadata only and change nothing that is computed."""
     k = cfg.buffer_k
 
-    def step(state: Dict[str, Any]) -> Dict[str, Any]:
+    # Not ``step``: JAX's persistent compilation cache keys a program with
+    # its metadata stripped, so this step would share its key with the
+    # step of earlier versions of this module, which had no scopes, and a
+    # cache that holds that executable would hand it back with no scope in
+    # its ops. The function's name is part of the key.
+    def serve_step(state: Dict[str, Any]) -> Dict[str, Any]:
         times, seqs = state["times"], state["seqs"]
-        popped_t, idx = pop_k_device(times, seqs, k)
-        clock = jnp.maximum(state["clock"], popped_t[-1])
+        with jax.named_scope(SCOPE_POP):
+            popped_t, idx = pop_k_device(times, seqs, k)
+            clock = jnp.maximum(state["clock"], popped_t[-1])
 
-        # staleness-discounted FedBuff weights, normalized on device
-        stale = (state["version"] - state["versions"][idx]).astype(
-            jnp.float32)
-        w = (1.0 + stale) ** (-cfg.staleness_power)
-        w = w / jnp.sum(w)
+            # staleness-discounted FedBuff weights, normalized on device
+            stale = (state["version"] - state["versions"][idx]).astype(
+                jnp.float32)
+            w = (1.0 + stale) ** (-cfg.staleness_power)
+            w = w / jnp.sum(w)
 
-        key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed),
-                                 state["next_seq"])
-        k_pay, k_lat = jax.random.split(key)
-        stacked = synthetic_payloads(cfg.spec, codec_params, k, k_pay)
-        mean = _decode_aggregate(cfg, codec_params, stacked, w)
-        global_flat = state["global_flat"] + cfg.server_lr * mean
+        # simulation only: a deployed server receives these bytes
+        with jax.named_scope(SCOPE_PAYLOADS):
+            key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed),
+                                     state["next_seq"])
+            k_pay, k_lat = jax.random.split(key)
+            stacked = synthetic_payloads(cfg.spec, codec_params, k, k_pay)
+
+        # the global update shares the decode's scope: XLA may fuse the add
+        # into the decoder's last matmul, and a fusion carries its root's
+        # metadata, so another scope here would take the whole matmul
+        with jax.named_scope(SCOPE_AGGREGATE):
+            mean = _decode_aggregate(cfg, codec_params, stacked, w)
+            global_flat = state["global_flat"] + cfg.server_lr * mean
 
         # re-dispatch exactly the drained cohort with the new model
-        lat = _latency(cfg, k_lat, idx)
-        new_seqs = state["next_seq"] + jnp.arange(k, dtype=jnp.int32)
-        return {
-            "times": times.at[idx].set(clock + lat),
-            "seqs": seqs.at[idx].set(new_seqs),
-            "versions": state["versions"].at[idx].set(
-                state["version"] + 1),
-            "global_flat": global_flat,
-            "clock": clock,
-            "version": state["version"] + 1,
-            "next_seq": state["next_seq"] + jnp.int32(k),
-        }
+        with jax.named_scope(SCOPE_REDISPATCH):
+            lat = _latency(cfg, k_lat, idx)
+            new_seqs = state["next_seq"] + jnp.arange(k, dtype=jnp.int32)
+            return {
+                "times": times.at[idx].set(clock + lat),
+                "seqs": seqs.at[idx].set(new_seqs),
+                "versions": state["versions"].at[idx].set(
+                    state["version"] + 1),
+                "global_flat": global_flat,
+                "clock": clock,
+                "version": state["version"] + 1,
+                "next_seq": state["next_seq"] + jnp.int32(k),
+            }
 
-    return jax.jit(step, donate_argnums=0)
+    return jax.jit(serve_step, donate_argnums=0)
 
 
 def round_bytes(cfg: ServeConfig,

@@ -6,13 +6,17 @@ hold round over round (clock monotone, version increments, exactly one
 in-flight dispatch per client), synthetic payloads have exactly the
 encode-shape structure, donation actually recycles buffers, and the step
 is deterministic (same config ⇒ same trajectory)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import codec
-from repro.core.serve import (ServeConfig, init_state, make_step,
+from repro.configs.paper import AEConfig
+from repro.core import codec, init_chunked_ae, init_fc_ae
+from repro.core.autoencoder import ChunkedAEConfig
+from repro.core.serve import (SCOPES, ServeConfig, init_state, make_step,
                               round_bytes, run_serve, synthetic_payloads)
 
 Q8 = codec.QuantizeSpec(size=512, bits=8, block=128)
@@ -150,3 +154,88 @@ def test_shard_single_device_matches_unsharded():
     np.testing.assert_allclose(np.asarray(sa["global_flat"]),
                                np.asarray(sb["global_flat"]),
                                rtol=1e-4, atol=1e-4)
+
+
+# ``  ROOT %name = <type> opcode(...)``: the non-greedy type stops at the
+# first `` opcode(``, which tuple types never hold
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? ([a-z][\w\-]*)\(")
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+) ")
+# computations the device runs instruction by instruction; a fusion's,
+# a sort's comparator or a reduction's body run inside their caller
+_RUN_BY = re.compile(r"(?:body|condition|true_computation|false_computation"
+                     r")=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_NO_WORK = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+
+_CHUNK = ChunkedAEConfig(chunk_size=128, hidden=(32,), latent_chunk=4)
+_CHUNK_AE = codec.ChunkedAESpec(size=1250, cfg=_CHUNK, use_kernel=False)
+_FC = AEConfig(input_dim=2048, encoder_hidden=(64,), latent_dim=16)
+
+
+def _device_instructions(text):
+    """``(opcode, op_name or None)`` of every instruction in the computations
+    the device runs one instruction at a time: ENTRY, and the bodies and
+    branches of its control flow."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            m = _COMPUTATION.match(line)
+            cur = m.group(2)
+            comps[cur] = []
+            if m.group(1):
+                entry = cur
+        elif cur is not None and _INSTRUCTION.match(line):
+            comps[cur].append(line)
+    runs, todo = {entry}, [entry]
+    while todo:
+        for line in comps[todo.pop()]:
+            names = _RUN_BY.findall(line)
+            b = _BRANCHES.search(line)
+            if b:
+                names += [n.strip().lstrip("%") for n in b.group(1).split(",")]
+            for n in names:
+                if n not in runs:
+                    runs.add(n)
+                    todo.append(n)
+    out = []
+    for c in runs:
+        for line in comps[c]:
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append((_INSTRUCTION.match(line).group(2),
+                        op.group(1) if op else None))
+    return out
+
+
+@pytest.mark.parametrize("case", ["chunked_ae_q8", "fc_ae", "topk_chain",
+                                  "q8_shard"])
+def test_step_ops_lie_under_one_scope(case):
+    """Every op the compiled step runs lies under exactly one of the four
+    layer scopes, so a trace's device time groups by layer. The copies that
+    XLA's copy insertion adds (loop state, donated buffers) carry no
+    metadata at all: they are the compiler's, not the program's."""
+    spec, params, shard = {
+        "chunked_ae_q8": (codec.ChainSpec((_CHUNK_AE, codec.QuantizeSpec(
+            size=_CHUNK_AE.n_chunks * 4, bits=8, block=8))),
+            (init_chunked_ae(jax.random.PRNGKey(0), _CHUNK), None), False),
+        "fc_ae": (codec.FCAESpec(size=1250, cfg=_FC),
+                  init_fc_ae(jax.random.PRNGKey(0), _FC), False),
+        "topk_chain": (codec.ChainSpec((codec.TopKSpec(size=1250, k=128),
+                                        codec.QuantizeSpec(size=128, bits=8,
+                                                           block=64))),
+                       None, False),
+        "q8_shard": (Q8, None, True),
+    }[case]
+    cfg = _cfg(n_clients=32, buffer_k=8, spec=spec, shard=shard)
+    text = make_step(cfg, params).lower(
+        init_state(cfg, params)).compile().as_text()
+    seen, stray = set(), []
+    for opcode, op_name in _device_instructions(text):
+        if opcode in _NO_WORK or (opcode == "copy" and op_name is None):
+            continue
+        hits = [p for p in (op_name or "").split("/") if p in SCOPES]
+        if len(hits) != 1:
+            stray.append((opcode, op_name))
+        seen.update(hits)
+    assert not stray, stray[:10]
+    assert seen == set(SCOPES)
