@@ -935,38 +935,53 @@ def decode_and_aggregate(spec: CodecSpec, params: Optional[Params],
     return jnp.einsum("c,cp->p", w, rows.astype(jnp.float32))
 
 
-def chunked_hidden(spec: ChunkedAESpec, params: Params,
-                   z: jax.Array) -> jax.Array:
+def chunked_hidden(spec: ChunkedAESpec, params: Params, z: jax.Array,
+                   rows: Optional[int] = None) -> jax.Array:
     """Kernel-path hidden decoder stack: ``(C, n_chunks, latent)`` latents →
-    ``(C, n_chunks, K)`` penultimate activations, everything latent-sided.
+    ``(C, rows, K)`` penultimate activations, everything latent-sided.
     Shared by the per-bucket fused path below and the grouped ragged launch
     (core/partition.py, DESIGN.md §11.2) — both then expand to chunk width
-    inside a weighted-accumulation kernel."""
+    inside a weighted-accumulation kernel.
+
+    ``rows`` (default ``n_chunks``) zero-pads each client's chunk axis on
+    the latent side, in the flat lane-dense ``(C, n_chunks·latent)`` form,
+    so the hidden activations come out of ``fused_dense`` already in the
+    row-padded layout the kernel reads (DESIGN.md §7.1); the padded rows
+    hold the decode of a zero latent, which the caller slices off."""
     from repro.kernels.fused_dense import fused_dense
     from repro.kernels.ops import interpret_default
     interp = interpret_default()
     C, nc, latent = z.shape
-    x = z.reshape(C * nc, latent)
+    rows = nc if rows is None else rows
+    x = z.reshape(C, nc * latent)
+    if rows != nc:
+        x = jnp.pad(x, ((0, 0), (0, (rows - nc) * latent)))
+    x = x.reshape(C * rows, latent)
     for layer in params["dec"][:-1]:           # hidden stack, act throughout
         # large bm: the folded (C·n_chunks) batch is tall and the hidden
         # widths narrow, so row-fat tiles stay far under VMEM while cutting
         # the grid-step count (which is what interpret-mode costs scale on)
         x = fused_dense(x, layer["w"], layer["b"],
                         act=spec.cfg.activation, bm=512, interpret=interp)
-    return x.reshape(C, nc, x.shape[-1])
+    return x.reshape(C, rows, x.shape[-1])
 
 
 def _fused_chunked_decode_agg(spec: ChunkedAESpec, params: Params,
                               z: jax.Array, weights: jax.Array) -> jax.Array:
     """ChunkedAE fused path: per-client work stays latent-sided (the hidden
-    stack output ``(C, n_chunks, hidden)``); the chunk_size-wide expansion
-    happens inside the weighted-accumulation kernel, once."""
-    from repro.kernels.fused_decode_agg import fused_decode_agg
+    stack output ``(C, Mp, hidden)``); the chunk_size-wide expansion
+    happens inside the weighted-accumulation kernel, once. The latents are
+    padded to the kernel plan's ``Mp`` rows, so the hidden activations are
+    written once in the layout the kernel reads; the ``Mp - n_chunks``
+    padded rows are sliced off before the denorm."""
+    from repro.kernels.fused_decode_agg import fused_decode_agg, padded_rows
     from repro.kernels.ops import interpret_default
     dec = params["dec"]
-    h = chunked_hidden(spec, params, z)
+    C, nc, _ = z.shape
+    K, N = dec[-1]["w"].shape
+    h = chunked_hidden(spec, params, z, rows=padded_rows(C, nc, K, N))
     chunks = fused_decode_agg(h, weights, dec[-1]["w"], dec[-1]["b"],
-                              interpret=interpret_default())
+                              interpret=interpret_default())[:nc]
     norm = params["norm"]                             # (nc, chunk_size)
     chunks = chunks * norm["std"] + norm["mean"]      # Σw=1 ⇒ mean denorm
     return chunks.reshape(-1)[:spec.size]

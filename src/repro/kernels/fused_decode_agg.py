@@ -35,6 +35,19 @@ at bc=16 and the v5e compiler refuses it; the plan runs bc=8, bn=2048
 against the pure-jnp oracle ``ref.fused_decode_agg_ref`` in interpret mode
 (DESIGN.md §7.3, tests/test_kernels.py).
 
+Row padding is the caller's to do. The launch pads each bucket's rows to a
+multiple of the plan's ``bm``; a caller that hands in ``h`` already at
+:func:`padded_rows` rows has nothing padded here (the launch then adds no
+pad op), so the hidden activations are written once, by the layer that
+makes them, in the layout this kernel reads. For the chunked AE that means
+padding the cohort's *latents* to the plan's rows before the hidden stack
+(``core/codec.py::_fused_chunked_decode_agg``): at C=1024, 135 chunks and
+hidden 512 the alternative is a 283 MB relayout of the hidden activations
+plus a pad to 144 rows. The padded rows decode to values no one reads; the
+caller slices the ``(Mp, N)`` output back to its real rows before the
+denorm. Every row of the decoder chain is independent, so the real rows
+see the same operations and tiles either way (DESIGN.md §7.1).
+
 Under per-layer codec partitions (DESIGN.md §10.2) the grouped server path
 launches this kernel once per kernel-path chunked-AE (partition, spec)
 bucket per round — ``M`` is then the *group's* chunk count, not the whole
@@ -95,6 +108,29 @@ def _tile_plan(C: int, M: int, K: int, N: int, bm: int,
         else:
             break
     return bm, bc, bn
+
+
+def _padded(M: int, bm: int) -> int:
+    return _cdiv(M, bm) * bm
+
+
+def padded_rows(C: int, M: int, K: int, N: int, *, bm: int = 128,
+                bc: int = 16) -> int:
+    """Rows ``Mp`` a one-bucket launch over ``C`` clients, ``M`` rows, hidden
+    width ``K`` and chunk width ``N`` pads to: the multiple of the tile
+    plan's row tile that :func:`grouped_fused_decode_agg` pads ``M`` to
+    with the same ``bm``/``bc`` caps (144 for the 135-row CIFAR bucket at
+    K=512, N=4096). ``padded_rows(C, padded_rows(C, M, ...), ...)`` is the
+    same ``Mp``, so an ``h`` built at these rows is launched unpadded."""
+    return _padded(M, _tile_plan(C, M, K, N, bm, bc)[0])
+
+
+def _pad_to(x: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
+    """Zero-pad ``x`` at the end of each axis up to ``shape``; no op where
+    it is there already."""
+    if x.shape == tuple(shape):
+        return x
+    return jnp.pad(x, [(0, n - m) for m, n in zip(x.shape, shape)])
 
 
 def _decode_agg_kernel(desc_ref, w_ref, h_ref, wl_ref, b_ref, o_ref):
@@ -180,7 +216,8 @@ def grouped_fused_decode_agg(hs: Sequence[jax.Array],
 
     Packing: client axis padded to the cohort-wide max block count (zero
     weight ⇒ exact zero contribution), each bucket's rows padded to a
-    ``bm`` multiple and laid end-to-end. A bucket with zero clients
+    ``bm`` multiple and laid end-to-end; a bucket already at those rows and
+    clients is not copied to pad it. A bucket with zero clients
     contributes nothing to the grid and returns exact zeros (its weight
     mass is zero, so the caller's scale-back drops it anyway).
 
@@ -203,7 +240,7 @@ def grouped_fused_decode_agg(hs: Sequence[jax.Array],
         assert 0 <= dec_idx[b] < D
     bm, bc, bn = _tile_plan(max(hs[b].shape[0] for b in live),
                             max(hs[b].shape[1] for b in live), K, N, bm, bc)
-    Cp = max(-(-hs[b].shape[0] // bc) * bc for b in live)
+    Cp = max(_padded(hs[b].shape[0], bc) for b in live)
 
     # pack: clients → shared padded axis, rows → bm-padded bands, and the
     # (bucket, row-block, decoder) descriptor column per grid tile
@@ -211,12 +248,9 @@ def grouped_fused_decode_agg(hs: Sequence[jax.Array],
     bucket_of, row_of, dec_of = [], [], []
     pos = 0
     for b in live:
-        C_b, M_b, _ = hs[b].shape
-        Mp_b = -(-M_b // bm) * bm
-        h_bands.append(jnp.pad(hs[b], ((0, Cp - C_b), (0, Mp_b - M_b),
-                                       (0, 0))))
-        w_rows.append(jnp.pad(weights[b].astype(jnp.float32),
-                              (0, Cp - C_b)))
+        Mp_b = _padded(hs[b].shape[1], bm)
+        h_bands.append(_pad_to(hs[b], (Cp, Mp_b, K)))
+        w_rows.append(_pad_to(weights[b].astype(jnp.float32), (Cp,)))
         offsets[b] = pos
         for i in range(Mp_b // bm):
             bucket_of.append(len(w_rows) - 1)   # row in the packed weights

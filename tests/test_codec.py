@@ -137,6 +137,44 @@ def test_decode_and_aggregate_matches_sequential(comp, use_base):
                                atol=1e-5 * scale, rtol=1e-5)
 
 
+@pytest.mark.parametrize("n_chunks", [5, 13, 135])
+@pytest.mark.parametrize("use_base", [False, True])
+def test_kernel_decode_and_aggregate_pads_latents_to_plan(n_chunks,
+                                                          use_base):
+    """Chunk counts that are not a multiple of 8: the kernel path pads the
+    latents to the kernel plan's rows (5→8, 13→16, 135→144, as the CIFAR
+    model's 135 chunks pad), decodes, and slices the padded rows off. It
+    must equal the pure-jnp decode then weighted_mean."""
+    from repro.kernels.fused_decode_agg import padded_rows
+    n = (n_chunks - 1) * _CHUNK_CFG.chunk_size + 77      # ragged last chunk
+    kspec = ChunkedAECompressor(_CHUNK_PARAMS, _CHUNK_CFG,
+                                use_kernel=True).spec(n)
+    jspec = ChunkedAECompressor(_CHUNK_PARAMS, _CHUNK_CFG,
+                                use_kernel=False).spec(n)
+    assert kspec.n_chunks == n_chunks
+    assert padded_rows(3, n_chunks, _CHUNK_CFG.hidden[-1],
+                       _CHUNK_CFG.chunk_size) > n_chunks
+    weights = [512.0, 317.0, 100.0]
+    flats = [jax.random.normal(jax.random.PRNGKey(i), (n,)) * (1.0 + i)
+             for i in range(3)]
+    payloads = [codec.encode(kspec, _CHUNK_PARAMS, f) for f in flats]
+    base = (jax.random.normal(jax.random.PRNGKey(99), (n,)) * 0.5
+            if use_base else None)
+    nw = jnp.asarray(normalize_weights(weights), jnp.float32)
+    got = codec.decode_and_aggregate(kspec, _CHUNK_PARAMS,
+                                     codec.stack_payloads(payloads), nw, base)
+
+    rows = [codec.decode(jspec, _CHUNK_PARAMS, pl) for pl in payloads]
+    if base is not None:
+        rows = [r - base for r in rows]
+    want, = jax.tree_util.tree_leaves(
+        weighted_mean([{"u": r} for r in rows], weights))
+    assert got.shape == (n,)
+    scale = float(jnp.max(jnp.abs(want))) + 1e-6
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5 * scale, rtol=1e-5)
+
+
 def test_decode_and_aggregate_per_client_params():
     """Per-client AE decoders ride a stacked params axis (params_batched)."""
     specs = [codec.FCAESpec(size=N, cfg=_FC_CFG)]

@@ -21,7 +21,8 @@ from repro.core import (ChunkedAECompressor, ChunkedAEConfig, FLConfig,
 from repro.core.scheduler import EncodedUpdate
 from repro.kernels import ops
 from repro.kernels.fused_decode_agg import (fused_decode_agg,
-                                            grouped_fused_decode_agg)
+                                            grouped_fused_decode_agg,
+                                            padded_rows)
 from repro.kernels.ref import grouped_fused_decode_agg_ref
 from repro.data.pipeline import (mnist_like, train_eval_split,
                                  uniform_partition)
@@ -53,6 +54,45 @@ def _mk_buckets(seed: int, cohort: int, rungs: int, K: int = 8, N: int = 32):
         ws.append((raw / raw.sum() if C_b else raw).astype(jnp.float32))
         dec_idx.append(r)
     return hs, ws, w_stack, b_stack, dec_idx
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in ``jaxpr`` and the jaxprs nested in it."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                out += _primitives(inner)
+    return out
+
+
+@pytest.mark.parametrize("M", [1, 7, 8, 135, 136, 144])
+@pytest.mark.parametrize("C", [1, 9, 64, 1024])
+def test_padded_rows_matches_launch_plan(C, M):
+    """``padded_rows`` is the row count the launch pads a bucket to, at the
+    production chunked AE's widths (K=512, N=4096); it is a fixed point,
+    and an ``h`` already at those rows enters the launch with no pad op."""
+    K, N = 512, 4096
+    Mp = padded_rows(C, M, K, N)
+    assert Mp >= M and Mp % 8 == 0
+    assert padded_rows(C, Mp, K, N) == Mp
+
+    def launch(m):
+        h = jax.ShapeDtypeStruct((C, m, K), jnp.float32)
+        w = jax.ShapeDtypeStruct((C,), jnp.float32)
+        wl = jax.ShapeDtypeStruct((K, N), jnp.float32)
+        bl = jax.ShapeDtypeStruct((N,), jnp.float32)
+        return jax.make_jaxpr(
+            lambda *a: grouped_fused_decode_agg(
+                [a[0]], [a[1]], a[2][None], a[3][None], [0]))(h, w, wl, bl)
+
+    jaxpr = launch(M).jaxpr
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.outvars[0].aval.shape == (Mp, N)
+    assert ("pad" in _primitives(jaxpr)) == (Mp != M)
+    assert "pad" not in _primitives(launch(Mp).jaxpr)
 
 
 @pytest.mark.parametrize("cohort", [1, 8, 64])

@@ -11,6 +11,7 @@ The persistent compilation cache is off around these compiles: an entry
 written for a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +96,69 @@ def test_grouped_fused_decode_agg_compiles_for_v5e(one_chip):
         _spec(one_chip, (7,)), _spec(one_chip, (2, K, N)),
         _spec(one_chip, (2, N)))
     assert "tpu_custom_call" in text
+
+
+def _entry_instructions(text: str):
+    """``(name, f32 dims or None, opcode, operand names)`` of each
+    instruction of the entry computation of compiled HLO text."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    out = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\(([^)]*)\)",
+                     line)
+        if m is None:
+            continue
+        name, ty, op, args = m.groups()
+        dims = re.fullmatch(r"f32\[([\d,]+)\]\S*", ty)
+        out.append((name,
+                    None if dims is None
+                    else tuple(int(d) for d in dims.group(1).split(",")),
+                    op, re.findall(r"%([\w.-]+)", args)))
+    return out
+
+
+def test_decode_and_aggregate_writes_hidden_once_for_v5e(one_chip,
+                                                         monkeypatch):
+    """The kernel-path decode→aggregate at the k1024 cohort (C=1024, the
+    CIFAR model's 135 chunks, hidden 512, chunk 4096, q8 latents): the
+    cohort's hidden activations are produced once, by ``fused_dense``,
+    already in the padded layout ``fused_decode_agg`` reads. No pad, copy
+    or relayout of them (f32 ``[C, r, K]`` or ``[C·r, K]``) lies between."""
+    from repro.core import codec
+    from repro.core.autoencoder import ChunkedAEConfig, init_chunked_ae
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    C, size = 1024, 550_586
+    cfg = ChunkedAEConfig(chunk_size=N, hidden=(K,), latent_chunk=8)
+    ae = codec.ChunkedAESpec(size=size, cfg=cfg, use_kernel=True)
+    assert ae.n_chunks == N_CHUNKS
+    spec = codec.ChainSpec((ae, codec.QuantizeSpec(
+        size=N_CHUNKS * cfg.latent_chunk, bits=8, block=64)))
+    params = (jax.eval_shape(
+        lambda: init_chunked_ae(jax.random.PRNGKey(0), cfg)), None)
+    one = jax.eval_shape(lambda p, x: codec.encode(spec, p, x), params,
+                         jax.ShapeDtypeStruct((size,), jnp.float32))
+    stacked = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, (C,) + a.shape, a.dtype), one)
+    text = _compiled_text(
+        lambda p, s, w: codec.decode_and_aggregate(spec, p, s, w),
+        jax.tree_util.tree_map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), params),
+        stacked, _spec(one_chip, (C,)))
+
+    instrs = _entry_instructions(text)
+    hidden = {name: op for name, dims, op, _ in instrs
+              if dims is not None and dims[-1] == K and (
+                  (len(dims) == 3 and dims[0] == C)
+                  or (len(dims) == 2 and dims[0] % C == 0))}
+    made = sorted(n for n, op in hidden.items() if op != "bitcast")
+    assert len(made) == 1 and made[0].startswith("fused_dense"), hidden
+    assert hidden[made[0]] == "custom-call"
+    readers = {name for name, _, op, args in instrs
+               if op != "bitcast" and any(a in hidden for a in args)}
+    assert len(readers) == 1 and next(iter(readers)).startswith(
+        "fused_decode_agg"), readers
 
 
 @pytest.mark.parametrize("nb,block,bits", [
