@@ -64,6 +64,10 @@ from bench.configs import reference
 from bench.harness import Cell, Check, Outcome
 
 CHECKS = ("state_mismatch", "times_err", "agg_err")
+# the step as the trace's ``XLA Modules`` line names it: ``program.serve_step``
+# jits a function named ``step``
+PROGRAMS = ("jit_step",)
+SCOPES = program.SCOPES
 STATE_KEYS = ("seqs", "versions", "clock", "version", "next_seq")
 
 # How the loop is run, in every cell; tests shorten them through ``run``.
@@ -103,6 +107,27 @@ def initial_state(cell: Cell, template: Dict, key: jax.Array) -> Dict:
         return state
 
     return jax.jit(draw)(key)
+
+
+def update_size(config: Dict) -> int:
+    """The parameter count of the configuration's classifier."""
+    return program.classifier_size(config["model"])
+
+
+def lower(cell: Cell) -> Dict[str, jax.stages.Lowered]:
+    """The cell's step lowered as :func:`run` calls it: the arguments left
+    on the default device, as the driver's are, so the lowering is the
+    driver's and its compile finds the driver's executable in the
+    persistent cache."""
+    spec = program.codec_spec(cell.codec)
+    cfg = program.serve_config(cell.traffic, spec)
+    state = jax.eval_shape(lambda: program.serve_init_state(cfg))
+    dec = jax.eval_shape(
+        lambda k: reference.make_decoder(cell.codec, cell.config["weights"],
+                                         k),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return {PROGRAMS[0]: program.serve_step(cell.codec, cfg).lower(state,
+                                                                    dec)}
 
 
 def program_step(cell: Cell, spec) -> Callable:

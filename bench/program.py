@@ -1,9 +1,12 @@
 """What the benchmark takes from the program: its codec specs and serve
-step, built from a configuration's codec section. Nothing else here is the
-program's; weights, traffic and the reference are the benchmark's own.
+step, built from a configuration's codec section, the serve step's scope
+names, and the classifier a configuration's model section describes.
+Nothing else here is the program's; weights, traffic and the reference
+are the benchmark's own.
 """
 from __future__ import annotations
 
+import math
 import os
 import sys
 from typing import Dict
@@ -14,6 +17,12 @@ import jax.numpy as jnp
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if os.path.join(ROOT, "src") not in sys.path:
     sys.path.insert(0, os.path.join(ROOT, "src"))
+
+from repro.core import serve as _serve  # noqa: E402
+
+# The serve step's layers, as the program names them with
+# ``jax.named_scope``; none for a program that names none.
+SCOPES = tuple(getattr(_serve, "SCOPES", ()))
 
 # The serve step's own PRNG seed. It is a constant of the compiled step, so
 # it stays fixed and every run finds the step in the compilation cache; a
@@ -109,3 +118,17 @@ def serve_step(codec: Dict, cfg):
 def serve_init_state(cfg):
     from repro.core.serve import init_state
     return init_state(cfg)
+
+
+def classifier_size(model: Dict) -> int:
+    """The parameter count of the classifier the program builds from a
+    configuration's model section (``repro.configs.paper.ClassifierConfig``
+    and ``init_classifier``), counted from shapes alone."""
+    from repro.configs.paper import ClassifierConfig
+    from repro.models.classifiers import init_classifier
+    cfg = ClassifierConfig(name="bench", **{
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in model.items() if k != "update_size"})
+    shapes = jax.eval_shape(lambda k: init_classifier(k, cfg),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return sum(math.prod(s.shape) for s in jax.tree_util.tree_leaves(shapes))

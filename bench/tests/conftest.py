@@ -5,8 +5,8 @@ chunked-AE q8 codec, kernels on) and ``data/scoped_fcae_k16`` (the FC AE).
 Off the chip, the scope reader (``bench/scopes.py``) takes a serve step's
 text from the recording of the same configuration and shape: a CPU compile
 names its instructions otherwise than the chip's, so the ops of a chip
-trace would find no scope. A step with no recording is compiled as on the
-chip."""
+trace would find no scope. Every other cell's programs are compiled as on
+the chip."""
 import gzip
 import os
 import shutil
@@ -19,6 +19,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from bench import scopes, trace  # noqa: E402
+from bench.drivers import serve  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 # configuration → recording
@@ -34,22 +35,22 @@ def recorded_text(config: str) -> str:
 
 
 @pytest.fixture(autouse=True)
-def recorded_step_texts(monkeypatch):
+def recorded_program_texts(monkeypatch):
     import jax
     monkeypatch.setattr(scopes, "_MAPS", {})
     if jax.devices()[0].platform == "tpu":
         return
-    compiled = scopes.step_text
+    compiled = scopes.program_texts
 
-    def step_text(cell):
+    def program_texts(cell):
         config = cell.config["name"]
         if (config in RECORDINGS
                 and cell.traffic["population"] == POPULATION
                 and cell.traffic["buffer_k"] == BUFFER_K):
-            return recorded_text(config)
+            return {serve.PROGRAMS[0]: recorded_text(config)}
         return compiled(cell)
 
-    monkeypatch.setattr(scopes, "step_text", step_text)
+    monkeypatch.setattr(scopes, "program_texts", program_texts)
 
 
 @pytest.fixture(scope="module", params=sorted(RECORDINGS))

@@ -12,9 +12,10 @@ rounds and ``updates_per_s`` of the window, device seconds and event
 counts by scope (``bench/scopes.py``) with the longest operations of
 each, and the device's idle gaps, those between two programs (``XLA
 Modules`` line) apart from those between the ops of one, with the rounds
-the host had dispatched and the device not yet started when each gap
-between programs began. Such a gap with rounds queued is the device's
-turnaround between programs; one with none is the host starving it.
+the host had dispatched and the device not yet started (runs of the
+driver's ``PROGRAMS``) when each gap between programs began. Such a gap
+with rounds queued is the device's turnaround between programs; one with
+none is the host starving it.
 With ``--out`` it also writes ``<out>.xplane.pb.gz``, the trace, and
 ``<out>.hlo.txt.gz``, the step's text, for ``test_scopes.py``. Needs the
 chip; sets up JAX as ``bench/run.py`` does.
@@ -36,11 +37,13 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 
 def by_scope(summary, scope_map, top=5):
-    """Device seconds, event counts and longest operations per scope."""
+    """Device seconds, event counts and longest operations per scope;
+    ``scope_map`` is keyed by program and instruction."""
     seconds, counts, ops = {}, {}, {}
     for dev in summary.ops:
         for e in dev:
-            s = scope_map.get(e.name, "not in the step")
+            s = scope_map.get((e.program, e.name),
+                              "not in the driver's programs")
             seconds[s] = seconds.get(s, 0.0) + e.dur_ns * 1e-9
             counts[s] = counts.get(s, 0) + 1
             per = ops.setdefault(s, {})
@@ -59,17 +62,16 @@ def _spread(us):
             "max_us": max(us)}
 
 
-def idle_gaps(path):
+def idle_gaps(path, programs):
     """The device's idle gaps in the window, split into those between two
     programs (``XLA Modules`` line) and those between the ops of one, and
     for each gap between programs the rounds queued when it began: the
-    ``bench.round`` dispatches ended on the host by then, less the
-    ``jit_step`` programs the device had started."""
-    from bench import scopes, trace
+    ``bench.round`` dispatches ended on the host by then, less the runs of
+    ``programs`` the device had started."""
+    from bench import trace
     tr = trace.load(path)
     lo, hi = tr.window()
-    mods = [(a, b, name.startswith(scopes.STEP_MODULE))
-            for a, b, name in scopes.programs(path)[0]]
+    mods = [(a, b, name in programs) for a, b, name in tr.runs[0]]
     rounds_done = sorted(s.end_ns for s in tr.spans
                          if s.name == "bench.round")
     starts = [a for a, _, _ in mods]
@@ -126,23 +128,27 @@ def main() -> int:
         out = harness.run_cell(cell, keep_trace=raw, sync_seconds=min(
             serve.SYNC_SECONDS, args.seconds))
         s = out.summary
-        gaps = idle_gaps(raw)
+        driver = harness.driver_of(cell)
+        gaps = idle_gaps(raw, driver.PROGRAMS)
         if args.out:
             with open(raw, "rb") as src, \
                     gzip.open(args.out + ".xplane.pb.gz", "wb") as dst:
                 shutil.copyfileobj(src, dst)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
-    text = scopes.step_text(cell)
+    texts = scopes.program_texts(cell)
     if args.out:
         with gzip.open(args.out + ".hlo.txt.gz", "wt") as f:
-            f.write(text)
+            f.write(texts[driver.PROGRAMS[0]])
+    prefix = scopes.family(driver.SCOPES)
+    scope_map = {(p, n): sc for p, text in texts.items()
+                 for n, sc in scopes.parse(text, prefix).items()}
     rounds = out.counters["rounds"]
     print(json.dumps({
         "workload": args.workload, "traffic": cell.traffic,
         "rounds": rounds, "window_s": s.window_s, "busy_s": s.busy_s,
         "updates_per_s": cell.traffic["buffer_k"] * rounds / s.window_s,
-        "scopes": by_scope(s, scopes.parse(text)), "idle_gaps": gaps,
+        "scopes": by_scope(s, scope_map), "idle_gaps": gaps,
         "checks": {c.name: c.value for c in out.checks}}))
     return 0
 

@@ -1,7 +1,8 @@
 """The harness's own arithmetic, on the CPU: every cell of
-``BENCHMARK.json`` resolves its files by name, the least-work counts match
-hand counts at the cells' shapes, and the trace reduction gives the busy
-time, idle gaps and per-name sums of a small trace recorded on a TPU v5e
+``BENCHMARK.json`` resolves its files by name and keeps to the driver
+contract, the least-work counts match hand counts at the cells' shapes,
+and the trace reduction gives the busy time, idle gaps, each op's program
+and the per-op sums of a small trace recorded on a TPU v5e
 (``data/serve_small.xplane.pb.gz``: a serve window of the chunked-AE q8 codec
 at K=16, N=4096, made by ``record_trace.py``)."""
 import json
@@ -19,18 +20,26 @@ from bench import harness, peaks, trace, work  # noqa: E402
 
 TRACE_GZ = os.path.join(os.path.dirname(__file__), "data",
                         "serve_small.xplane.pb.gz")
+SERVE_CELLS = ("serve_chunkae_k1024", "serve_fcae_k256",
+               "serve_chunkae_n1m_k64")
+CIFAR_CONFIGS = ("cifar_chunkae_q8", "cifar_fcae")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 @pytest.fixture(scope="module")
-def chip_trace(tmp_path_factory):
+def chip_trace_path(tmp_path_factory):
     """The recorded trace, unpacked (it is kept gzipped)."""
     import gzip
     import shutil
     path = tmp_path_factory.mktemp("trace") / "serve_small.xplane.pb"
     with gzip.open(TRACE_GZ, "rb") as src, open(path, "wb") as dst:
         shutil.copyfileobj(src, dst)
-    return trace.load(str(path))
+    return str(path)
+
+
+@pytest.fixture(scope="module")
+def chip_trace(chip_trace_path):
+    return trace.load(chip_trace_path)
 
 
 @pytest.fixture(scope="module")
@@ -48,7 +57,10 @@ def test_every_cell_resolves_its_files(bench):
         traffic = harness.load_json("traffic", w["traffic"] + ".json")
         harness.load_module("drivers", traffic["driver"])
         limits = harness.load_json("limits", w["name"] + ".json")
-        assert set(limits) == {"state_mismatch", "times_err", "agg_err"}
+        if w["name"] in SERVE_CELLS:
+            assert set(limits) == {"state_mismatch", "times_err", "agg_err"}
+    assert SERVE_CELLS == tuple(w["name"] for w in bench["workloads"]
+                                if w["name"] in SERVE_CELLS)
     for m in bench["per_layer"]:
         assert callable(harness.load_module("metrics", m["name"]).read)
         assert set(m["workloads"]) <= {w["name"] for w in bench["workloads"]}
@@ -70,7 +82,19 @@ def test_config_file_is_the_config_as_run(bench):
     for c in bench["configs"]:
         cfg = harness.load_json("configs", c["name"] + ".json")
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
-        assert cfg["model"]["update_size"] == 550_586
+        if c["name"] in CIFAR_CONFIGS:
+            assert cfg["model"]["update_size"] == 550_586
+    serve = harness.load_module("drivers", "serve")
+    for name in CIFAR_CONFIGS:
+        cfg = harness.load_json("configs", name + ".json")
+        assert serve.update_size(cfg) == 550_586
+
+
+def test_the_benchmark_keeps_the_driver_contract(bench):
+    """Each cell's limits are its driver's ``CHECKS``, each configuration
+    states the update size its driver counts, and each per-layer metric
+    lists cells that exist."""
+    assert harness.contract_problems(bench) == []
 
 
 CHUNK = harness.codec_of(harness.load_json("configs",
@@ -116,10 +140,11 @@ def test_least_time_takes_the_larger_bound():
 
 
 def _sweep(tr):
-    """Busy time and per-name sums by another route: a sweep over every
-    event boundary inside the window with a count of open events."""
+    """Busy time and per-op sums, by ``(program, name)``, by another route:
+    a sweep over every event boundary inside the window with a count of
+    open events."""
     lo, hi = tr.window()
-    evs = [(max(e.start_ns, lo), min(e.end_ns, hi), e.name)
+    evs = [(max(e.start_ns, lo), min(e.end_ns, hi), (e.program, e.name))
            for e in tr.ops[0] if min(e.end_ns, hi) > max(e.start_ns, lo)]
     marks = sorted([(s, 1) for s, _, _ in evs] + [(t, -1) for _, t, _ in evs])
     busy, open_, last = 0.0, 0, lo
@@ -145,8 +170,19 @@ def test_trace_reduction_on_a_chip_trace(chip_trace):
     idle = sum(b - a for a, b in trace.gaps(s.ops[0], lo, hi))
     assert (idle + busy) * 1e-9 == pytest.approx(s.window_s, rel=1e-9)
     top = dict((n, v) for n, v in s.breakdown["device_ops"])
-    for n, v in top.items():
-        assert v == pytest.approx(sums[n] * 1e-9, rel=1e-9)
+    programs = {}
+    for p, n in sums:
+        programs.setdefault(n, []).append(p)
+    for label, v in top.items():
+        # a name that one program has stands alone; a shared one is
+        # ``program:name``
+        p, _, n = label.rpartition(":")
+        if p:
+            assert len(programs[n]) > 1
+        else:
+            assert len(programs[n]) == 1
+            p = programs[n][0]
+        assert v == pytest.approx(sums[p, n] * 1e-9, rel=1e-9)
     assert sum(sums.values()) * 1e-9 >= s.busy_s * (1 - 1e-9)
     assert all(g[0].startswith("bench.") or g[0] == "none"
                for g in s.breakdown["idle_gaps"])
@@ -176,3 +212,44 @@ def test_metric_readers_on_a_chip_trace(bench, chip_trace):
         assert value is not None and value > 0.0, m["name"]
         if m["unit"] == "%":
             assert value <= 100.0, (m["name"], value)
+
+
+def test_each_op_keeps_its_program(chip_trace, chip_trace_path):
+    """Every device op carries the program whose run on the ``XLA
+    Modules`` line contains it, found here by a scan over every run; in
+    this window the step and the harness's copies share instruction names
+    (``copy-done``), which the breakdown then puts apart."""
+    from jax.profiler import ProfileData
+    plane = next(p for p in ProfileData.from_file(chip_trace_path).planes
+                 if p.name.startswith("/device:TPU:"))
+    runs = [(e.start_ns, e.start_ns + e.duration_ns, e.name.split("(")[0])
+            for line in plane.lines if line.name == "XLA Modules"
+            for e in line.events]
+    ops = chip_trace.ops[0]
+    for e in ops:
+        inside = [p for a, b, p in runs if a <= e.start_ns and e.end_ns <= b]
+        assert inside == [e.program], (e, inside)
+    assert {e.program for e in ops} == {"jit_step", "jit__lambda"}
+    by_name = {}
+    for e in ops:
+        by_name.setdefault(e.name, set()).add(e.program)
+    assert by_name["copy-done"] == {"jit_step", "jit__lambda"}
+    assert all(p == {"jit_step"} for n, p in by_name.items()
+               if n.startswith("fused_dense"))
+
+
+def test_shared_names_are_put_apart_by_program():
+    """Ops of two programs with one instruction name are summed and named
+    apart; a name that one program has keeps its bare name."""
+    ev = trace.Event
+    tr = trace.Trace(
+        ops=[[ev("fusion.3", 0, 10, "jit_a"), ev("fusion.3", 20, 30, "jit_b"),
+              ev("sort", 60, 5, "jit_a"), ev("fusion.3", 70, 10, "jit_a")]],
+        spans=[ev("bench.window", 0, 100)], runs=[[]])
+    s = trace.summarize(tr)
+    names, seconds = zip(*s.breakdown["device_ops"])
+    assert names == ("jit_b:fusion.3", "jit_a:fusion.3", "sort")
+    assert seconds == pytest.approx((30e-9, 20e-9, 5e-9))
+    assert s.op_seconds(lambda n: n == "fusion.3") == pytest.approx(50e-9)
+    assert s.program_op_seconds(
+        lambda p, n: (p, n) == ("jit_a", "fusion.3")) == pytest.approx(20e-9)

@@ -1,8 +1,11 @@
 """The scope readers (``bench/scopes.py``) on the CPU: the parse of a
 step's text into scopes, the checks a map has to pass before any metric
 reads it, and every scope metric on the two serve windows recorded on a
-TPU v5e with the compiled text of their step (``conftest.py``)."""
+TPU v5e with the compiled text of their step (``conftest.py``), against
+the rule by instruction name alone that the map by program replaced."""
+import collections
 import os
+import re
 import sys
 
 import pytest
@@ -12,9 +15,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from bench import harness, scopes  # noqa: E402
+from bench.drivers import serve  # noqa: E402
 from conftest import (BUFFER_K, POPULATION, RECORDINGS,  # noqa: E402
                       recorded_text)
 from repro.core.serve import SCOPES  # noqa: E402
+
+PREFIX = scopes.family(SCOPES)
 
 HAND_WRITTEN = """\
 HloModule jit_step, entry_computation_layout={(f32[4]{0})->f32[4]{0}}
@@ -43,7 +49,8 @@ def test_parse_of_a_hand_written_module():
     ``op_name``; one without metadata takes its source's scope, else its
     first consumer's; the program's own ops outside every scope, and the
     parameters, stay unscoped."""
-    m = scopes.parse(HAND_WRITTEN)
+    assert PREFIX == "serve."
+    m = scopes.parse(HAND_WRITTEN, PREFIX)
     assert m == {
         "param_0": "serve.aggregate",   # its consumer's (inside a fusion)
         "add.1": "serve.aggregate",
@@ -57,8 +64,8 @@ def test_parse_of_a_hand_written_module():
         "negate": scopes.UNSCOPED,
         "tuple": "serve.pop",           # its first scoped source's
     }
-    assert scopes.scope_of("jit(step)/serve.pop/jit(f)/serve.x/sort") \
-        == "serve.pop"
+    assert scopes.scope_of("jit(step)/serve.pop/jit(f)/serve.x/sort",
+                           PREFIX) == "serve.pop"
 
 
 @pytest.fixture(scope="module")
@@ -67,7 +74,7 @@ def bench():
 
 
 def test_recorded_text_names_every_scope(recording):
-    m = scopes.parse(recording["text"])
+    m = scopes.parse(recording["text"], PREFIX)
     assert set(SCOPES) <= set(m.values())
     assert m["sort"] == "serve.pop"
     if recording["config"] == "cifar_chunkae_q8":
@@ -77,11 +84,20 @@ def test_recorded_text_names_every_scope(recording):
         assert kernels and all(m[n] == "serve.aggregate" for n in kernels)
 
 
+def _step_ops(recording, lo=float("-inf"), hi=float("inf")):
+    """``(name, seconds)`` of every device op of the recording's trace that
+    ran inside a run of the driver's programs and started inside
+    ``[lo, hi]``."""
+    return [(e.name, e.dur_ns * 1e-9) for dev in recording["trace"].ops
+            for e in dev
+            if e.program in serve.PROGRAMS and lo <= e.start_ns <= hi]
+
+
 def test_scopes_cover_the_step(recording):
     """At least 99% of the device time of the step's programs maps to one
     of the four scopes."""
-    m = scopes.parse(recording["text"])
-    ops = scopes.step_ops(recording["path"])
+    m = scopes.parse(recording["text"], PREFIX)
+    ops = _step_ops(recording)
     total = sum(s for _, s in ops)
     mapped = sum(s for n, s in ops if m.get(n) in SCOPES)
     assert total > 0.0
@@ -127,8 +143,7 @@ def test_metric_readers_on_scoped_recordings(bench, recording):
         assert read["aggregate_roofline"] <= read["decode_agg_roofline"]
     rounds = ctx.counters["rounds"]
     lo, hi = recording["trace"].window()
-    step_ms = 1e3 * sum(s for _, s in scopes.step_ops(recording["path"], lo,
-                                                      hi)) / rounds
+    step_ms = 1e3 * sum(s for _, s in _step_ops(recording, lo, hi)) / rounds
     scoped_ms = sum(scopes.ms_per_round(ctx, s) or 0.0 for s in SCOPES)
     assert scoped_ms == pytest.approx(step_ms, rel=0.02)
 
@@ -152,11 +167,116 @@ def test_a_map_that_fails_its_checks_gives_no_metric(bench, recording,
     else:
         text = recording["text"].replace("serve.redispatch/",
                                          "serve.elsewhere/")
-    monkeypatch.setattr(scopes, "step_text", lambda cell: text)
+    monkeypatch.setattr(scopes, "program_texts",
+                        lambda cell: {serve.PROGRAMS[0]: text})
     ctx = _context(bench, recording)
     if case == "other_program":
-        assert scopes.coverage(ctx.summary, scopes.parse(text)) \
+        m = {(serve.PROGRAMS[0], n): s
+             for n, s in scopes.parse(text, PREFIX).items()}
+        assert scopes.coverage(ctx.summary, m, serve.PROGRAMS) \
             < scopes.MIN_COVERAGE
     for name in _scope_metrics(bench):
         assert harness.load_module("metrics", name).read(ctx) is None, name
 
+
+
+# The rule the map by program replaced, as it stood: each instruction of
+# the step's text takes the first ``serve.*`` component of its ``op_name``
+# (else the scope of what it copies from, else into), keyed by instruction
+# name alone, so that it also placed another program's op of that name.
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? [a-z][\w\-]*\(")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _by_name(text):
+    def first_serve(op_name):
+        return next((p for p in op_name.split("/")
+                     if p.startswith("serve.")), scopes.UNSCOPED)
+
+    def operands(line, start):
+        depth = 0
+        for i in range(start, len(line)):
+            depth += {"(": 1, ")": -1}.get(line[i], 0)
+            if depth == 0:
+                return _OPERAND.findall(line, start, i)
+        return _OPERAND.findall(line, start)
+
+    own, srcs, users = {}, {}, {}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, op = m.group(1), _OP_NAME.search(line)
+        own[name] = first_serve(op.group(1)) if op else None
+        srcs[name] = operands(line, m.end() - 1)
+        for o in srcs[name]:
+            users.setdefault(o, []).append(name)
+
+    def inherit(name, edges):
+        seen, todo = {name}, collections.deque(edges.get(name, ()))
+        while todo:
+            n = todo.popleft()
+            if n in seen or n not in own:
+                continue
+            seen.add(n)
+            if own[n] is None:
+                todo.extend(edges.get(n, ()))
+            elif own[n] != scopes.UNSCOPED:
+                return own[n]
+        return None
+
+    return {n: s if s is not None
+            else inherit(n, srcs) or inherit(n, users) or scopes.UNSCOPED
+            for n, s in own.items()}
+
+
+def test_map_by_program_agrees_with_the_rule_by_name(bench, recording,
+                                                      monkeypatch):
+    """On the step's program the map by program is the rule by name, entry
+    for entry. Every scoped metric reads, bit for bit, what the rule by
+    name reads over the step's own ops; the rule by name also counted the
+    harness's copies (``_copy``, a program of its own) whose instruction
+    names the step shares, and its ``scope_ms.*`` readings exceed these by
+    exactly their time. The coverage is no lower than the rule by name's,
+    which counted those copies in its total."""
+    ctx = _context(bench, recording)
+    new = scopes.scope_map(ctx.cell)
+    old = _by_name(recording["text"])
+    assert {p for p, _ in new} == set(serve.PROGRAMS)
+    assert {n: s for (p, n), s in new.items()} == old
+    s, rounds = ctx.summary, ctx.counters["rounds"]
+    copies = {sc: s.program_op_seconds(
+        lambda p, n: p not in serve.PROGRAMS and old.get(n) == sc)
+        for sc in SCOPES}
+    assert any(copies.values())     # the recordings do share names
+
+    def rule_by_name(step_only):
+        def scope_seconds(ctx, scope):
+            return ctx.summary.program_op_seconds(
+                lambda p, n: (p in serve.PROGRAMS or not step_only)
+                and old.get(n) == scope)
+        return scope_seconds
+
+    cells = {w["name"] for w in bench["workloads"]
+             if w["config"] == recording["config"]}
+    current = scopes.scope_seconds
+    for name in _scope_metrics(bench):
+        if not cells & set(next(m for m in bench["per_layer"]
+                                if m["name"] == name)["workloads"]):
+            continue
+        reader = harness.load_module("metrics", name)
+        value = reader.read(ctx)
+        monkeypatch.setattr(scopes, "scope_seconds", rule_by_name(True))
+        assert reader.read(ctx) == value, name
+        monkeypatch.setattr(scopes, "scope_seconds", rule_by_name(False))
+        by_name = reader.read(ctx)
+        monkeypatch.setattr(scopes, "scope_seconds", current)
+        if name.startswith("scope_ms."):
+            extra = 1e3 * copies["serve." + name.split(".", 1)[1]] / rounds
+            assert by_name - value == pytest.approx(extra, rel=1e-9,
+                                                    abs=1e-15), name
+    old_share = (s.op_seconds(lambda n: old.get(n, scopes.UNSCOPED)
+                              != scopes.UNSCOPED)
+                 / s.op_seconds(lambda n: True))
+    assert scopes.coverage(s, new, serve.PROGRAMS) >= old_share

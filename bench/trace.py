@@ -9,9 +9,18 @@ line. An operation's
 event carries the HLO instruction's whole text (``%fused_dense.1 =
 f32[...] custom-call(...)``); it is named here by the instruction alone
 (``fused_dense.1``), since the operand list names other instructions.
+
+Instruction names are unique within one compiled program, not across
+programs: XLA names a fusion ``fusion.3`` in each. So every operation also
+keeps its program, the run on the plane's ``XLA Modules`` line that
+contains it (``jit_step(1235368921123069479)`` names the program
+``jit_step``), and a breakdown puts the program in front of a name only
+where two programs of the window share it (``jit_step:fusion.3``).
 """
 from __future__ import annotations
 
+import bisect
+import collections
 import dataclasses
 import glob
 import os
@@ -20,6 +29,8 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
 OPS_LINE = "XLA Ops"
+# one event per run of a compiled program
+MODULES_LINE = "XLA Modules"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +38,7 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
+    program: str = ""               # a device op's program; "" for a span
 
     @property
     def end_ns(self) -> float:
@@ -37,6 +49,7 @@ class Event:
 class Trace:
     ops: List[List[Event]]          # per device, operations
     spans: List[Event]              # host spans named bench.*
+    runs: List[List[Tuple[float, float, str]]]   # per device, program runs
 
     def window(self) -> Tuple[float, float]:
         w = [s for s in self.spans if s.name == WINDOW_SPAN]
@@ -51,6 +64,11 @@ def op_name(text: str) -> str:
     return text.split(" = ", 1)[0].lstrip("%")
 
 
+def program_of(module: str) -> str:
+    """``jit_step(1235368921123069479)`` → ``jit_step``."""
+    return module.split("(", 1)[0]
+
+
 def find_xplane(directory: str) -> str:
     paths = glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
                       recursive=True)
@@ -63,19 +81,32 @@ def find_xplane(directory: str) -> str:
 def load(path: str) -> Trace:
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    ops, spans = [], []
+    ops, spans, runs = [], [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
-            ops.append([Event(op_name(e.name), e.start_ns, e.duration_ns)
-                        for line in plane.lines if line.name == OPS_LINE
-                        for e in line.events])
+            lines = {line.name: list(line.events) for line in plane.lines}
+            runs.append(sorted(
+                (e.start_ns, e.start_ns + e.duration_ns, program_of(e.name))
+                for e in lines.get(MODULES_LINE, ())))
+            ops.append([Event(op_name(e.name), e.start_ns, e.duration_ns,
+                              program_in(runs[-1], e.start_ns,
+                                         e.start_ns + e.duration_ns))
+                        for e in lines.get(OPS_LINE, ())])
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith(SPAN_PREFIX):
                         spans.append(Event(e.name, e.start_ns,
                                            e.duration_ns))
-    return Trace(ops=ops, spans=spans)
+    return Trace(ops=ops, spans=spans, runs=runs)
+
+
+def program_in(runs: Sequence[Tuple[float, float, str]], start: float,
+               end: float) -> str:
+    """The program of the run in ``runs`` (in order, none overlapping)
+    that contains ``[start, end]``; "" where none does."""
+    i = bisect.bisect_right(runs, (start, float("inf"))) - 1
+    return runs[i][2] if i >= 0 and end <= runs[i][1] else ""
 
 
 def clip(events: Iterable[Event], lo: float, hi: float) -> List[Event]:
@@ -84,7 +115,7 @@ def clip(events: Iterable[Event], lo: float, hi: float) -> List[Event]:
     for e in events:
         s, t = max(e.start_ns, lo), min(e.end_ns, hi)
         if t > s:
-            out.append(Event(e.name, s, t - s))
+            out.append(dataclasses.replace(e, start_ns=s, dur_ns=t - s))
     return out
 
 
@@ -103,11 +134,21 @@ def busy_ns(events: Sequence[Event]) -> float:
     return sum(b - a for a, b in union(events))
 
 
-def sums_by_name(events: Iterable[Event]) -> Dict[str, float]:
-    out: Dict[str, float] = {}
+def sums_by_op(events: Iterable[Event]) -> Dict[Tuple[str, str], float]:
+    """Summed durations by ``(program, name)``."""
+    out: Dict[Tuple[str, str], float] = {}
     for e in events:
-        out[e.name] = out.get(e.name, 0.0) + e.dur_ns
+        key = (e.program, e.name)
+        out[key] = out.get(key, 0.0) + e.dur_ns
     return out
+
+
+def labels(keys: Iterable[Tuple[str, str]]) -> Dict[Tuple[str, str], str]:
+    """A breakdown's name of each ``(program, name)``: the name alone where
+    no other program of ``keys`` has it, else ``program:name``."""
+    keys = list(keys)
+    count = collections.Counter(name for _, name in keys)
+    return {(p, n): n if count[n] == 1 else f"{p}:{n}" for p, n in keys}
 
 
 def gaps(events: Sequence[Event], lo: float, hi: float
@@ -144,9 +185,14 @@ class Summary:
     def op_seconds(self, match) -> float:
         """Device seconds, averaged over devices, of the operations whose
         instruction name ``match`` accepts."""
+        return self.program_op_seconds(lambda program, name: match(name))
+
+    def program_op_seconds(self, match) -> float:
+        """Device seconds, averaged over devices, of the operations whose
+        program and instruction name ``match(program, name)`` accepts."""
         n = max(len(self.ops), 1)
         return sum(e.dur_ns for dev in self.ops for e in dev
-                   if match(e.name)) / n * 1e-9
+                   if match(e.program, e.name)) / n * 1e-9
 
 
 def summarize(trace: Trace, top: int = 10) -> Summary:
@@ -156,11 +202,12 @@ def summarize(trace: Trace, top: int = 10) -> Summary:
         raise ValueError("no device operation inside the traced window")
     n = len(ops)
     busy = sum(busy_ns(dev) for dev in ops) / n
-    totals: Dict[str, float] = {}
+    totals: Dict[Tuple[str, str], float] = {}
     for dev in ops:
-        for k, v in sums_by_name(dev).items():
+        for k, v in sums_by_op(dev).items():
             totals[k] = totals.get(k, 0.0) + v / n
-    device_ops = [[k, v * 1e-9] for k, v in
+    label = labels(totals)
+    device_ops = [[label[k], v * 1e-9] for k, v in
                   sorted(totals.items(), key=lambda kv: -kv[1])[:top]]
     longest = sorted(((b - a, (a + b) / 2) for dev in ops
                       for a, b in gaps(dev, lo, hi)), reverse=True)[:top]
